@@ -71,7 +71,7 @@ def bench_row(arch: str, K: int, remat: bool, J: int, reps: int,
     jax.block_until_ready((carry, aux))
     ms = (time.perf_counter() - t0) / reps * 1e3
 
-    mem = eng._jit_step.lower(carry, xs, eng._store).compile(
+    mem = eng.lower(carry, xs, scanned=False).compile(
         ).memory_analysis()
     row = {"arch": arch, "K": K, "remat": remat, "J": J, "reps": reps,
            "dataset": dataset, "n_per_client": n_per_client,

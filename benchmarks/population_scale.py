@@ -123,7 +123,7 @@ def bench_K(K: int, J: int, reps: int, dataset: str = "iemocap",
     jax.block_until_ready((carry, aux))
     ms = (time.perf_counter() - t0) / reps * 1e3
 
-    mem = eng._jit_step.lower(carry, xs, eng._store).compile(
+    mem = eng.lower(carry, xs, scanned=False).compile(
         ).memory_analysis()
     store_mb = sum(np.asarray(x).nbytes
                    for x in jax.tree.leaves(eng._store)) / 2 ** 20

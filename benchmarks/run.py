@@ -46,18 +46,26 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import time
+import traceback
+from typing import Optional
 
 import numpy as np
+
+from repro.launch.compile_cache import enable_compile_cache
 
 ROWS = []
 PAYLOADS = {}          # raw per-benchmark result dicts, for --json-out
 TINY = False
 
 
-def emit(name: str, us_per_call: float, derived: str = ""):
+def emit(name: str, us_per_call: Optional[float], derived: str = ""):
+    """One CSV row; ``us_per_call=None`` leaves the timing column empty
+    (error rows carry no timing)."""
     ROWS.append((name, us_per_call, derived))
-    print(f"{name},{us_per_call:.1f},{derived}", flush=True)
+    us = "" if us_per_call is None else f"{us_per_call:.1f}"
+    print(f"{name},{us},{derived}", flush=True)
 
 
 def _time(fn, n=3, warmup=1):
@@ -388,6 +396,7 @@ def main() -> None:
     ap.add_argument("--json-out", default=None,
                     help="dump emitted rows + raw payloads as JSON")
     args, _ = ap.parse_known_args()
+    enable_compile_cache()
     TINY = args.tiny
     quick = not args.full
     benches = {
@@ -408,13 +417,16 @@ def main() -> None:
     if args.v_frontier:
         args.only = "v_frontier"
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in benches.items():
         if args.only and name != args.only:
             continue
         try:
             fn(quick)
-        except Exception as e:  # keep the harness running
-            emit(f"{name}_ERROR", 0.0, f"{type(e).__name__}:{e}")
+        except Exception as e:  # record it, run the other benches, exit 1
+            traceback.print_exc()
+            emit(f"{name}_ERROR", None, f"{type(e).__name__}:{e}")
+            failed.append(name)
     if args.v_frontier and "v_frontier" in PAYLOADS:
         with open("BENCH_v_frontier.json", "w") as f:
             json.dump(PAYLOADS["v_frontier"], f, indent=2)
@@ -426,6 +438,8 @@ def main() -> None:
         with open(args.json_out, "w") as f:
             json.dump(payload, f, indent=2)
         print(f"wrote {args.json_out}", flush=True)
+    if failed:
+        sys.exit(f"benches raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -23,6 +23,7 @@ from repro.core.aggregation import unified_weights
 from repro.core.convergence import BoundState
 from repro.data.tokens import TokenStream
 from repro.launch import steps
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw
 from repro.wireless import cost as wcost
 from repro.wireless.channel import Channel
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=128)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config("qwen3-0.6b").reduced()
     K = args.pods

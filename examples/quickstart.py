@@ -10,9 +10,11 @@ import numpy as np
 from repro.fl.runtime import MFLExperiment
 from repro.configs import get_config
 from repro.launch import steps
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # --- the paper's system: decision-fusion MFL over a simulated cell ---
     exp = MFLExperiment(dataset="crema_d", scheduler="jcsba",
                         n_samples=400, seed=0)

@@ -10,6 +10,7 @@ import os
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.fl.runtime import MFLExperiment
 
 
@@ -35,6 +36,7 @@ def main():
                          "scalar path — host loops only)")
     ap.add_argument("--out", default="examples/out_wireless_mfl.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     eval_every = 4
     results = {}

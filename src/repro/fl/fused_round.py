@@ -275,10 +275,6 @@ class FusedRoundEngine:
         # per-client cost vector
         tmpl["tau_cmp"] = np.asarray(exp.cost.tau_cmp, np.float64)
         self._solver_tmpl = to_device(tmpl)
-        self._has = self._solver_tmpl["has"]            # [M, K] bool
-        self._D = self._solver_tmpl["D"]                # [K] f32
-        self._tau_cmp = self._solver_tmpl["tau_cmp"]
-        self._e_cmp = self._solver_tmpl["e_cmp"]
         p = exp.params
         self._tau_max = float(p.tau_max)
         self._E_add = float(p.E_add)
@@ -294,8 +290,10 @@ class FusedRoundEngine:
 
         # device-resident eval context: the held-out split lives on device
         # for the engine's lifetime; rounds flagged by xs.eval_flag run the
-        # shared fl.eval.eval_metrics program on the fresh globals
-        self._test_feats, self._test_labels = device_test_set(exp.test_ds)
+        # shared fl.eval.eval_metrics program on the fresh globals.  It enters
+        # every program as an argument, never as a baked-in constant, so the
+        # compiled code (and its cache key) does not depend on the data.
+        self._test_set = device_test_set(exp.test_ds)
         self._compile()
 
     @classmethod
@@ -343,10 +341,6 @@ class FusedRoundEngine:
             "D": sizes,
         }
         self._solver_tmpl = to_device(tmpl)
-        self._has = self._solver_tmpl["has"]
-        self._D = self._solver_tmpl["D"]
-        self._tau_cmp = self._solver_tmpl["tau_cmp"]
-        self._e_cmp = self._solver_tmpl["e_cmp"]
         self._tau_max = float(params.tau_max)
         self._E_add = float(params.E_add)
         self._p_tx = float(params.p_tx)
@@ -361,8 +355,8 @@ class FusedRoundEngine:
         # eval context: client 0's shard stands in as the held-out split —
         # population benches never flag an eval round, but lax.cond still
         # traces both branches, so the program needs *some* test tensors
-        self._test_feats = {m: self._store.features[m][0] for m in self.mods}
-        self._test_labels = self._store.labels[0]
+        self._test_set = ({m: self._store.features[m][0] for m in self.mods},
+                          self._store.labels[0])
         self._compile()
         return self
 
@@ -431,9 +425,8 @@ class FusedRoundEngine:
     # ------------------------------------------------------------------
     # the fused program
     # ------------------------------------------------------------------
-    def _round_step(self, carry: FusedCarry, xs: RoundXs, store,
-                    overrides=None, test_set=None,
-                    axis_name: Optional[str] = None):
+    def _round_step(self, carry: FusedCarry, xs: RoundXs, store, test_set,
+                    overrides=None, axis_name: Optional[str] = None):
         """One round.  ``store`` is the (possibly shard-local)
         ``ClientStore``; ``axis_name`` names the mesh axis the store and the
         per-client xs leaves are sharded over (None = single device /
@@ -442,11 +435,11 @@ class FusedRoundEngine:
 
         ``overrides`` replaces solver-template entries for this round (a
         vmapped V — or, for scenario grids, any per-scenario context:
-        gamma/tau_rem/tau_cmp/e_cmp/has/D/wbar...); ``test_set`` is an
-        optional ``(features, labels)`` pair replacing the engine's static
-        held-out split, so scenario grids evaluate each scenario on its own
-        test data."""
+        gamma/tau_rem/tau_cmp/e_cmp/has/D/wbar...); ``test_set`` is the
+        ``(features, labels)`` held-out split the round evaluates on — the
+        engine's own, or a scenario's in a scenario grid."""
         self.trace_count += 1
+        tf, tl = test_set
 
         # 0. under a client-sharded mesh the *vector* physics stays dense +
         # replicated: reassemble the full channel draw from the shards
@@ -555,8 +548,6 @@ class FusedRoundEngine:
         # 7. device-resident eval of the fresh globals on the held-out split
         # (the host loop's adapter.evaluate, fused behind the cadence flag —
         # only the branch that actually runs costs anything at runtime)
-        tf, tl = test_set if test_set is not None else \
-            (self._test_feats, self._test_labels)
         metrics = lax.cond(
             xs.eval_flag,
             lambda p: eval_metrics(p, tf, tl, logits_fn=self._eval_logits),
@@ -568,23 +559,29 @@ class FusedRoundEngine:
         aux = RoundAux(a, ok, J, w, spent.sum(), drop, metrics, xs.eval_flag)
         return new_carry, aux
 
-    def _scan_steps(self, carry: FusedCarry, xs: RoundXs, store):
+    def _scan_steps(self, carry: FusedCarry, xs: RoundXs, store, test_set):
         def body(c, x):
-            return self._round_step(c, x, store)
+            return self._round_step(c, x, store, test_set)
         return lax.scan(body, carry, xs)
 
     # ------------------------------------------------------------------
     def step(self, carry: FusedCarry, xs: RoundXs):
-        return self._jit_step(carry, xs, self._store)
+        return self._jit_step(carry, xs, self._store, self._test_set)
 
     def scan(self, carry: FusedCarry, xs: RoundXs):
         """R rounds in one program; xs leaves carry a leading [R] axis.
         Compiles once per distinct R (then cached)."""
-        return self._jit_scan(carry, xs, self._store)
+        return self._jit_scan(carry, xs, self._store, self._test_set)
+
+    def lower(self, carry: FusedCarry, xs: RoundXs, scanned: bool = True):
+        """The ``jax.stages.Lowered`` round program ``scan`` (or ``step``)
+        runs — for memory analysis and for inspecting the compiled text."""
+        fn = self._jit_scan if scanned else self._jit_step
+        return fn.lower(carry, xs, self._store, self._test_set)
 
     def _scan_one_v(self, V, carry: FusedCarry, xs: RoundXs, store,
-                    axis_name: Optional[str] = None):
-        return self._scan_one_scenario({"V": V}, store, None, carry, xs,
+                    test_set, axis_name: Optional[str] = None):
+        return self._scan_one_scenario({"V": V}, store, test_set, carry, xs,
                                        axis_name=axis_name)
 
     def _scan_one_scenario(self, overrides, store, test_set,
@@ -594,8 +591,8 @@ class FusedRoundEngine:
         this scenario's solver-data overrides / store / test split.  The unit
         ``scan_scenario_grid`` vmaps and shards."""
         def body(c, x):
-            return self._round_step(c, x, store, overrides=overrides,
-                                    test_set=test_set, axis_name=axis_name)
+            return self._round_step(c, x, store, test_set,
+                                    overrides=overrides, axis_name=axis_name)
         return lax.scan(body, carry, xs)
 
     def scan_scenario_grid(self, overrides, carry: FusedCarry, xs: RoundXs,
@@ -633,7 +630,7 @@ class FusedRoundEngine:
                     f"expected {n_S}")
         store_arg = self._store if stores is None else \
             jax.tree.map(jnp.asarray, stores)
-        ts_arg = None if test_sets is None else \
+        ts_arg = self._test_set if test_sets is None else \
             jax.tree.map(jnp.asarray, test_sets)
         if mesh == "auto":
             mesh = make_sweep_mesh()
@@ -707,7 +704,23 @@ class FusedRoundEngine:
             # V is just the simplest scenario grid — one overridden solver
             # entry, engine store and test split shared by every row
             return self.scan_scenario_grid({"V": V}, carry, xs, mesh=mesh)
-        n_V = V.shape[0]
+        fn, args = self._population_sweep(V, carry, xs, mesh)
+        carries, auxs = fn(*args)
+        return (slice_leading_axis(carries, V.shape[0]),
+                slice_leading_axis(auxs, V.shape[0]))
+
+    def lower_v_grid(self, V_grid, carry: FusedCarry, xs: RoundXs, mesh):
+        """The ``jax.stages.Lowered`` program ``scan_v_grid`` runs on a 2-D
+        ``("scenario", "clients")`` mesh; its compiled ``input_shardings``
+        show which device holds which slice of the store and of the
+        per-client randomness."""
+        fn, args = self._population_sweep(
+            jnp.asarray(V_grid, jnp.float32), carry, xs, mesh)
+        return fn.lower(*args)
+
+    def _population_sweep(self, V, carry: FusedCarry, xs: RoundXs, mesh):
+        """The jitted 2-D-mesh sweep and its arguments (V padded to the
+        scenario axis)."""
         n_cl = int(mesh.shape["clients"])
         if self.K % n_cl:
             raise ValueError(
@@ -718,7 +731,7 @@ class FusedRoundEngine:
         if fn is None:
             vm = jax.vmap(
                 functools.partial(self._scan_one_v, axis_name="clients"),
-                in_axes=(0, None, None, None))
+                in_axes=(0, None, None, None, None))
             xs_spec = RoundXs(
                 h=logical_pspec(("rounds", "clients"), mesh),
                 draw_seed=logical_pspec(("rounds",), mesh),
@@ -727,12 +740,10 @@ class FusedRoundEngine:
             fn = jax.jit(population_shard_map(
                 vm, mesh,
                 in_specs=(logical_pspec(("scenario",), mesh), P(),
-                          xs_spec, logical_pspec(("clients",), mesh)),
+                          xs_spec, logical_pspec(("clients",), mesh), P()),
                 out_specs=logical_pspec(("scenario",), mesh)))
             self._sharded_vsweep_cache[mesh] = fn
-        carries, auxs = fn(Vp, carry, xs, self._store)
-        return (slice_leading_axis(carries, n_V),
-                slice_leading_axis(auxs, n_V))
+        return fn, (Vp, carry, xs, self._store, self._test_set)
 
     # ------------------------------------------------------------------
     def run(self, carry: FusedCarry, xs: RoundXs, scanned: bool):

@@ -1,4 +1,4 @@
-"""jit'd wrapper: layout adaptation [B,S,H,hd] <-> [B,H,S,hd] + CPU fallback.
+"""jit'd wrapper: layout adaptation [B,S,H,hd] <-> [B,H,S,hd].
 
 ``models.layers.attention_fwd`` can be pointed at this implementation on TPU
 (``attention_impl="pallas"`` in the serving/training drivers); the dry-run and
@@ -9,8 +9,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
+from .. import interpret_mode
 from .kernel import flash_attention_pallas
 
 
@@ -18,8 +17,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     interpret: Optional[bool] = None, **kw):
     """q: [B, S, H, hd]; k/v: [B, S, K, hd] (models.layers layout)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)

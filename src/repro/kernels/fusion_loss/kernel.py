@@ -27,8 +27,9 @@ space; see core.convergence for the param-space twin).
 Grid: (T/Tb, V/Vb), vocab innermost; streaming state lives in VMEM scratch
 across vocab tiles.  Per-modality logits arrive as separate refs (variadic),
 so callers never materialise an [M, T, V] stack in HBM; a broadcast head
-(e.g. vision [B, 1, V] against labels [B, S]) is fed as its compact [B, V]
-array with a tile→batch-row index map (``seg[m] = S``, requires Tb | S).
+(e.g. vision [B, 1, V] against labels [B, S]) is fed as its compact
+[B, 1, V] array with a tile→batch-row index map (``seg[m] = S``, requires
+Tb | S).
 """
 from __future__ import annotations
 
@@ -42,31 +43,29 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _load_stack(logit_refs, bt: int, bv: int):
-    """Stack the per-modality tiles in VMEM ([M, Tb, Vb], f32).  A broadcast
-    modality's tile is [1, Vb] and broadcasts over the token rows."""
-    return jnp.stack([jnp.broadcast_to(r[...].astype(jnp.float32), (bt, bv))
-                      for r in logit_refs])
+def _load_tiles(logit_refs, bt: int):
+    """The per-modality tiles as f32 [Tb, Vb] values.  A broadcast head's
+    tile is [1, Vb] and broadcasts over the token rows.  Every value stays
+    2-D (rows on sublanes, vocab on lanes): Mosaic lays out no 1-D vectors
+    and no [M, Tb, Vb] stacks."""
+    return [jnp.broadcast_to(r[...].astype(jnp.float32), (bt, r.shape[-1]))
+            for r in logit_refs]
 
 
 def _gold_pick(labels, iv, block_v: int):
-    """Bool [Tb, Vb]: True where this vocab tile holds the gold column."""
-    idx = labels - iv * block_v
-    in_tile = (idx >= 0) & (idx < block_v)
-    safe = jnp.clip(idx, 0, block_v - 1)
-    onehot = (jax.lax.broadcasted_iota(jnp.int32,
-                                       (labels.shape[0], block_v), 1)
-              == safe[:, None])
-    return jnp.where(in_tile[:, None], onehot, False)
+    """Bool [Tb, Vb]: True where this vocab tile holds the gold column of
+    the row (``labels`` is [Tb, 1])."""
+    col = jax.lax.broadcasted_iota(jnp.int32, (labels.shape[0], block_v), 1)
+    return col + iv * block_v == labels
 
 
-def _fused_tile(logits, avail, iv, block_v: int, v_real: int):
+def _fused_tile(xs, avail, iv, block_v: int, v_real: int):
     """Availability-averaged mixture tile with padded vocab columns pinned to
     NEG_INF (keeps the fused LSE independent of vocab padding even on rows
-    where every modality is unavailable and the mixture degenerates to 0)."""
-    denom = jnp.maximum(avail.sum(0), 1e-9)                 # [Tb]
-    fused = (jnp.einsum("mtv,mt->tv", logits, avail)
-             / denom[:, None])                              # [Tb, Vb]
+    where every modality is unavailable and the mixture degenerates to 0).
+    ``xs``/``avail``: per-modality [Tb, Vb] tiles and [Tb, 1] weights."""
+    denom = jnp.maximum(sum(avail), 1e-9)                   # [Tb, 1]
+    fused = sum(x * a for x, a in zip(xs, avail)) / denom   # [Tb, Vb]
     col = (jax.lax.broadcasted_iota(jnp.int32, fused.shape, 1)
            + iv * block_v)
     return jnp.where(col < v_real, fused, NEG_INF), denom
@@ -94,53 +93,58 @@ def _fwd_kernel(labels_ref, avail_ref, *refs, n_mod: int, block_v: int,
         gm[...] = jnp.zeros_like(gm)
 
     bt = labels_ref.shape[0]
-    logits = _load_stack(logit_refs, bt, block_v)           # [M, Tb, Vb]
-    avail = avail_ref[...].astype(jnp.float32)              # [M, Tb]
-    labels = labels_ref[...]                                # [Tb]
-    fused, _ = _fused_tile(logits, avail, iv, block_v, v_real)
+    xs = _load_tiles(logit_refs, bt)                        # M × [Tb, Vb]
+    avail = [avail_ref[i].astype(jnp.float32) for i in range(n_mod)]
+    pick = _gold_pick(labels_ref[...], iv, block_v)
+    fused, _ = _fused_tile(xs, avail, iv, block_v, v_real)
 
-    # --- streaming logsumexp: fused ---
-    tile_max = fused.max(axis=-1)                           # [Tb]
-    m_new = jnp.maximum(mf[...], tile_max)
+    # streaming logsumexp + gold logit: fused mixture, then each modality
+    m_new = jnp.maximum(mf[...], fused.max(axis=-1, keepdims=True))
     sf[...] = (sf[...] * jnp.exp(mf[...] - m_new)
-               + jnp.exp(fused - m_new[:, None]).sum(-1))
+               + jnp.exp(fused - m_new).sum(-1, keepdims=True))
     mf[...] = m_new
-
-    # --- streaming logsumexp: per modality ---
-    t_max = logits.max(axis=-1)                             # [M, Tb]
-    mm_new = jnp.maximum(mm[...], t_max)
-    sm[...] = (sm[...] * jnp.exp(mm[...] - mm_new)
-               + jnp.exp(logits - mm_new[..., None]).sum(-1))
-    mm[...] = mm_new
-
-    # --- gold logit extraction (label may fall in this vocab tile) ---
-    pick = _gold_pick(labels, iv, block_v)
-    gf[...] = gf[...] + jnp.where(pick, fused, 0.0).sum(-1)
-    gm[...] = gm[...] + jnp.where(pick[None], logits, 0.0).sum(-1)
+    gf[...] = gf[...] + jnp.where(pick, fused, 0.0).sum(-1, keepdims=True)
+    for i, x in enumerate(xs):
+        mi_new = jnp.maximum(mm[i], x.max(axis=-1, keepdims=True))
+        sm[i] = (sm[i] * jnp.exp(mm[i] - mi_new)
+                 + jnp.exp(x - mi_new).sum(-1, keepdims=True))
+        mm[i] = mi_new
+        gm[i] = gm[i] + jnp.where(pick, x, 0.0).sum(-1, keepdims=True)
 
     @pl.when(iv == nv - 1)
     def _finalize():
         f_lse = mf[...] + jnp.log(sf[...])
-        m_lse = mm[...] + jnp.log(sm[...])
-        outs[0][...] = (f_lse - gf[...]).astype(outs[0].dtype)
-        outs[1][...] = ((m_lse - gm[...]) * avail).astype(outs[1].dtype)
+        outs[0][...] = f_lse - gf[...]
+        for i in range(n_mod):
+            m_lse = mm[i] + jnp.log(sm[i])
+            outs[1][i] = (m_lse - gm[i]) * avail[i]
+            if save_residuals:
+                outs[4][i] = mm[i]
+                outs[5][i] = m_lse
         if save_residuals:
             outs[2][...] = mf[...]
             outs[3][...] = f_lse
-            outs[4][...] = mm[...]
-            outs[5][...] = m_lse
+
+
+def _row_specs(M: int, block_t: int):
+    """BlockSpecs of a per-row [T, 1] and a per-(modality, row) [M, T, 1]
+    operand: a trailing unit lane dim keeps every block 2-D and tileable,
+    under the cohort vmap's extra leading axis too."""
+    return (pl.BlockSpec((block_t, 1), lambda it, iv: (it, 0)),
+            pl.BlockSpec((M, block_t, 1), lambda it, iv: (0, it, 0)))
 
 
 def _logit_specs(seg, block_t: int, block_v: int):
     """Per-modality input BlockSpecs.  ``seg[m] == 0`` → full [T, V] operand
-    tiled (Tb, Vb); ``seg[m] == S`` → compact [B, V] operand whose token tile
-    maps onto one batch row (requires Tb | S so tiles never straddle rows)."""
+    tiled (Tb, Vb); ``seg[m] == S`` → compact [B, 1, V] operand whose token
+    tile maps onto one batch row (requires Tb | S so tiles never straddle
+    rows)."""
     specs = []
     for s in seg:
         if s:
             assert s % block_t == 0, (s, block_t)
             specs.append(pl.BlockSpec(
-                (1, block_v),
+                (pl.squeezed, 1, block_v),
                 functools.partial(_seg_map, bt=block_t, S=s)))
         else:
             specs.append(pl.BlockSpec((block_t, block_v),
@@ -149,7 +153,12 @@ def _logit_specs(seg, block_t: int, block_v: int):
 
 
 def _seg_map(it, iv, *, bt: int, S: int):
-    return ((it * bt) // S, iv)
+    return ((it * bt) // S, 0, iv)
+
+
+def _logit_operands(logits, seg):
+    """Broadcast heads [B, V] enter the kernel as [B, 1, V]."""
+    return [lg[:, None, :] if s else lg for lg, s in zip(logits, seg)]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -170,43 +179,27 @@ def fusion_loss_fwd_pallas(logits, labels, avail, *, block_t: int,
     V = logits[0].shape[-1]
     assert T % block_t == 0 and V % block_v == 0, (T, V, block_t, block_v)
     grid = (T // block_t, V // block_v)
-
-    row = lambda it, iv: (it,)                              # noqa: E731
-    mrow = lambda it, iv: (0, it)                           # noqa: E731
-    out_specs = [pl.BlockSpec((block_t,), row),
-                 pl.BlockSpec((M, block_t), mrow)]
-    out_shape = [jax.ShapeDtypeStruct((T,), jnp.float32),
-                 jax.ShapeDtypeStruct((M, T), jnp.float32)]
+    row, mrow = _row_specs(M, block_t)
+    out_specs = [row, mrow]
+    out_shape = [jax.ShapeDtypeStruct((T, 1), jnp.float32),
+                 jax.ShapeDtypeStruct((M, T, 1), jnp.float32)]
     if save_residuals:
-        out_specs += [pl.BlockSpec((block_t,), row),
-                      pl.BlockSpec((block_t,), row),
-                      pl.BlockSpec((M, block_t), mrow),
-                      pl.BlockSpec((M, block_t), mrow)]
-        out_shape += [jax.ShapeDtypeStruct((T,), jnp.float32),
-                      jax.ShapeDtypeStruct((T,), jnp.float32),
-                      jax.ShapeDtypeStruct((M, T), jnp.float32),
-                      jax.ShapeDtypeStruct((M, T), jnp.float32)]
+        out_specs += [row, row, mrow, mrow]
+        out_shape += out_shape[:1] * 2 + out_shape[1:] * 2
 
     kern = functools.partial(_fwd_kernel, n_mod=M, block_v=block_v,
                              v_real=v_real, save_residuals=save_residuals)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[pl.BlockSpec((block_t,), row),
-                  pl.BlockSpec((M, block_t), mrow)]
-                 + _logit_specs(seg, block_t, block_v),
+        in_specs=[row, mrow] + _logit_specs(seg, block_t, block_v),
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_t,), jnp.float32),       # mf
-            pltpu.VMEM((block_t,), jnp.float32),       # sf
-            pltpu.VMEM((block_t,), jnp.float32),       # gf
-            pltpu.VMEM((M, block_t), jnp.float32),     # mm
-            pltpu.VMEM((M, block_t), jnp.float32),     # sm
-            pltpu.VMEM((M, block_t), jnp.float32),     # gm
-        ],
+        scratch_shapes=[pltpu.VMEM((block_t, 1), jnp.float32)] * 3
+                       + [pltpu.VMEM((M, block_t, 1), jnp.float32)] * 3,
         interpret=interpret,
-    )(labels, avail, *logits)
+    )(labels[:, None], avail[..., None], *_logit_operands(logits, seg))
+    return tuple(o[..., 0] for o in out)
 
 
 # ---------------------------------------------------------------------------
@@ -214,42 +207,35 @@ def fusion_loss_fwd_pallas(logits, labels, avail, *, block_t: int,
 # ---------------------------------------------------------------------------
 def _bwd_kernel(labels_ref, avail_ref, df_ref, dm_ref, flse_ref, mlse_ref,
                 *refs, n_mod: int, block_v: int, v_real: int):
-    it = pl.program_id(0)
     iv = pl.program_id(1)
-    ni = pl.num_programs(0)
     nv = pl.num_programs(1)
     logit_refs = refs[:n_mod]
     dl_refs = refs[n_mod:2 * n_mod]
-    gsq_ref, gdot_ref = refs[2 * n_mod:2 * n_mod + 2]
-    sq_acc, dot_acc = refs[2 * n_mod + 2:]
+    gsq_ref, gdot_ref, sq_acc, dot_acc = refs[2 * n_mod:]
 
-    @pl.when((it == 0) & (iv == 0))
+    @pl.when(iv == 0)
     def _init():
         sq_acc[...] = jnp.zeros_like(sq_acc)
         dot_acc[...] = jnp.zeros_like(dot_acc)
 
     bt = labels_ref.shape[0]
-    logits = _load_stack(logit_refs, bt, block_v)           # [M, Tb, Vb]
-    avail = avail_ref[...].astype(jnp.float32)              # [M, Tb]
-    labels = labels_ref[...]
-    df = df_ref[...].astype(jnp.float32)                    # [Tb]
-    dm = dm_ref[...].astype(jnp.float32)                    # [M, Tb]
-    fused, denom = _fused_tile(logits, avail, iv, block_v, v_real)
+    xs = _load_tiles(logit_refs, bt)                        # M × [Tb, Vb]
+    avail = [avail_ref[i].astype(jnp.float32) for i in range(n_mod)]
+    fused, denom = _fused_tile(xs, avail, iv, block_v, v_real)
 
     # probabilities from the saved residuals, one tile at a time
-    p_f = jnp.exp(fused - flse_ref[...][:, None])           # [Tb, Vb]
-    p_m = jnp.exp(logits - mlse_ref[...][..., None])        # [M, Tb, Vb]
-    pick = _gold_pick(labels, iv, block_v).astype(jnp.float32)
-    base = df[:, None] * (p_f - pick)                       # [Tb, Vb]
-    d = ((avail / denom)[..., None] * base[None]
-         + (dm * avail)[..., None] * (p_m - pick[None]))    # [M, Tb, Vb]
+    p_f = jnp.exp(fused - flse_ref[...])                    # [Tb, Vb]
+    pick = _gold_pick(labels_ref[...], iv, block_v).astype(jnp.float32)
+    base = df_ref[...] * (p_f - pick)                       # [Tb, Vb]
+    for i, (x, r) in enumerate(zip(xs, dl_refs)):
+        p_m = jnp.exp(x - mlse_ref[i])
+        d = ((avail[i] / denom) * base
+             + (dm_ref[i] * avail[i]) * (p_m - pick))       # [Tb, Vb]
+        r[...] = d.astype(r.dtype)
+        sq_acc[i] = sq_acc[i] + (d * d).sum(-1, keepdims=True)
+        dot_acc[i] = dot_acc[i] + (d * base).sum(-1, keepdims=True)
 
-    for i, r in enumerate(dl_refs):
-        r[...] = d[i].astype(r.dtype)
-    sq_acc[...] = sq_acc[...] + (d * d).sum((1, 2))
-    dot_acc[...] = dot_acc[...] + (d * base[None]).sum((1, 2))
-
-    @pl.when((it == ni - 1) & (iv == nv - 1))
+    @pl.when(iv == nv - 1)
     def _finalize():
         gsq_ref[...] = sq_acc[...]
         gdot_ref[...] = dot_acc[...]
@@ -267,38 +253,32 @@ def fusion_loss_bwd_pallas(logits, labels, avail, d_fused, d_modal,
     the loss cotangents ``d_fused`` [T] / ``d_modal`` [M, T] and the saved
     LSE residuals.  Returns (dlogits — one [T, V] f32 array per modality,
     broadcast heads included; gsq [M] = Σ dx_m²; gdot [M] = Σ dx_m·g_fused).
+    The kernel accumulates the partials per row across vocab tiles; the
+    row sum happens here.
     """
     M = len(logits)
     T = labels.shape[0]
     V = logits[0].shape[-1]
     assert T % block_t == 0 and V % block_v == 0, (T, V, block_t, block_v)
     grid = (T // block_t, V // block_v)
-
-    row = lambda it, iv: (it,)                              # noqa: E731
-    mrow = lambda it, iv: (0, it)                           # noqa: E731
-    acc = lambda it, iv: (0,)                               # noqa: E731
+    row, mrow = _row_specs(M, block_t)
     kern = functools.partial(_bwd_kernel, n_mod=M, block_v=block_v,
                              v_real=v_real)
     out = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[pl.BlockSpec((block_t,), row),
-                  pl.BlockSpec((M, block_t), mrow),
-                  pl.BlockSpec((block_t,), row),
-                  pl.BlockSpec((M, block_t), mrow),
-                  pl.BlockSpec((block_t,), row),
-                  pl.BlockSpec((M, block_t), mrow)]
+        in_specs=[row, mrow, row, mrow, row, mrow]
                  + _logit_specs(seg, block_t, block_v),
         out_specs=[pl.BlockSpec((block_t, block_v),
-                                lambda it, iv: (it, iv))] * M
-                  + [pl.BlockSpec((M,), acc), pl.BlockSpec((M,), acc)],
+                                lambda it, iv: (it, iv))] * M + [mrow] * 2,
         out_shape=[jax.ShapeDtypeStruct((T, V), jnp.float32)] * M
-                  + [jax.ShapeDtypeStruct((M,), jnp.float32)] * 2,
-        scratch_shapes=[pltpu.VMEM((M,), jnp.float32),
-                        pltpu.VMEM((M,), jnp.float32)],
+                  + [jax.ShapeDtypeStruct((M, T, 1), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((M, block_t, 1), jnp.float32)] * 2,
         interpret=interpret,
-    )(labels, avail, d_fused, d_modal, fused_lse, modal_lse, *logits)
-    return tuple(out[:M]), out[M], out[M + 1]
+    )(labels[:, None], avail[..., None], d_fused[:, None],
+      d_modal[..., None], fused_lse[:, None], modal_lse[..., None],
+      *_logit_operands(logits, seg))
+    return tuple(out[:M]), out[M].sum((1, 2)), out[M + 1].sum((1, 2))
 
 
 # ---------------------------------------------------------------------------

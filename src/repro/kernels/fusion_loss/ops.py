@@ -20,10 +20,10 @@ changes the G_m weighting semantics and stays on the XLA path.
 
 Non-divisible ``block_t``/``block_v`` tiles are handled by padding: token
 rows pad with avail = 0 (exact-zero loss and gradient), vocab columns pad
-with a large-negative logit (exactly zero probability mass).  On CPU both
-directions transparently fall back to interpret mode (the TPU kernel is the
-deploy target); metrics omit ``fused_logits`` (the kernel never forms the
-fused logits tensor — use the XLA path when you need it for accuracy).
+with a large-negative logit (exactly zero probability mass).  Off a TPU both
+directions run in interpret mode (``kernels.interpret_mode``).  Metrics
+omit ``fused_logits`` (the kernel never forms the fused logits tensor — use
+the XLA path when you need it for accuracy).
 """
 from __future__ import annotations
 
@@ -35,19 +35,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import interpret_mode
 from .kernel import (fusion_loss_bwd_pallas, fusion_loss_fwd_pallas,
                      fusion_loss_pallas)
 
 __all__ = ["fusion_loss", "fusion_loss_grads", "fused_multimodal_loss",
            "fusion_loss_pallas"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    return (not _on_tpu()) if interpret is None else bool(interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -161,7 +154,7 @@ def fusion_loss(logits, labels, avail=None, *, block_t: int = 128,
     M, T, V = logits.shape
     if avail is None:
         avail = jnp.ones((M, T), jnp.float32)
-    cfg = (block_t, block_v, _resolve_interpret(interpret), (0,) * M)
+    cfg = (block_t, block_v, interpret_mode(interpret), (0,) * M)
     return _fusion_core(cfg, tuple(logits[i] for i in range(M)),
                         labels.astype(jnp.int32),
                         avail.astype(jnp.float32))
@@ -179,7 +172,7 @@ def fusion_loss_grads(logits, labels, avail, d_fused, d_modal, *,
     tile-by-tile inside the same single pass that emits the gradient
     (float64-oracle parity in tests/test_fusion_vjp.py)."""
     M, T, V = logits.shape
-    cfg = (block_t, block_v, _resolve_interpret(interpret), (0,) * M)
+    cfg = (block_t, block_v, interpret_mode(interpret), (0,) * M)
     lg = tuple(logits[i] for i in range(M))
     labels = labels.astype(jnp.int32)
     avail = avail.astype(jnp.float32)
@@ -229,7 +222,7 @@ def fused_multimodal_loss(modal_logits: Mapping[str, jax.Array],
         avs.append(a)
     a_full = jnp.broadcast_to(jnp.stack(avs)[:, None], (len(names), T))
 
-    cfg = (block_t, block_v, _resolve_interpret(interpret), tuple(seg))
+    cfg = (block_t, block_v, interpret_mode(interpret), tuple(seg))
     f_nll, m_nll = _fusion_core(cfg, tuple(lgs), lab, a_full)
 
     if sample_mask is None:

@@ -9,7 +9,7 @@ Outputs
   modal_nll: [M, T] f32 — per-modality CE (Eq. 3), zero where unavailable
 
 The ``*_f64`` twins run the same math in float64 (when jax x64 is enabled —
-tests wrap them in ``jax.experimental.enable_x64``) and serve as the gradient
+tests wrap them in ``jax.enable_x64(True)``) and serve as the gradient
 oracle for the custom-VJP Pallas backward: ``fusion_loss_ref_grads`` emits
 the logits cotangent and the ζ/δ partials (gsq = ‖dx_m‖², gdot = ⟨dx_m,
 g_fused⟩) by materialising the softmax probabilities the kernel never does.

@@ -19,28 +19,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(x_ref, cum_ref, b_ref, c_ref, y_ref, st_ref, *, Q: int):
-    x = x_ref[0, 0, :, 0, :].astype(jnp.float32)           # [Q, hp]
-    cum = cum_ref[0, 0, :, 0].astype(jnp.float32)          # [Q]
-    Bm = b_ref[0, 0].astype(jnp.float32)                   # [Q, N]
-    Cm = c_ref[0, 0].astype(jnp.float32)                   # [Q, N]
+def _kernel(x_ref, cumc_ref, cumr_ref, bt_ref, c_ref, y_ref, st_ref):
+    x = x_ref[...].astype(jnp.float32)                     # [Q, hp]
+    cum_c = cumc_ref[...].astype(jnp.float32)              # [Q, 1]
+    cum_r = cumr_ref[...].astype(jnp.float32)              # [1, Q]
+    Bt = bt_ref[...].astype(jnp.float32)                   # [N, Q]
+    Cm = c_ref[...].astype(jnp.float32)                    # [Q, N]
+    Q = x.shape[0]
 
     tri = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
            >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
     # mask the exponent: upper-tri diffs overflow exp (cf. mamba2.py note)
-    L = jnp.exp(jnp.where(tri, cum[:, None] - cum[None, :], -jnp.inf))
-    scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-    w = L * scores                                         # [Q, Q]
-    y = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    y_ref[0, 0, :, 0, :] = y.astype(y_ref.dtype)
+    L = jnp.exp(jnp.where(tri, cum_c - cum_r, -jnp.inf))
+    scores = jnp.dot(Cm, Bt, preferred_element_type=jnp.float32)  # [Q, Q]
+    y = jnp.dot(L * scores, x, preferred_element_type=jnp.float32)
+    y_ref[...] = y.astype(y_ref.dtype)
 
-    decay_end = jnp.exp(cum[-1] - cum)                     # [Q]
-    xw = x * decay_end[:, None]
-    st = jax.lax.dot_general(Bm, xw, (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)   # [N, hp]
-    st_ref[0, 0, 0] = st.astype(st_ref.dtype)
+    decay_end = jnp.exp(cumc_ref[pl.ds(Q - 1, 1), :] - cum_c)   # [Q, 1]
+    st = jnp.dot(Bt, x * decay_end,
+                 preferred_element_type=jnp.float32)       # [N, hp]
+    st_ref[...] = st.astype(st_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -48,27 +46,37 @@ def ssd_chunk_pallas(x, cum, Bm, Cm, *, interpret: bool = False):
     """x: [B,nc,Q,nh,hp] (dt-weighted), cum: [B,nc,Q,nh], Bm/Cm: [B,nc,Q,N].
 
     Returns (y_diag [B,nc,Q,nh,hp] f32, states [B,nc,nh,N,hp] f32).
+
+    The kernel sees head-major operands — x as [.., nh, Q, hp], cum as a
+    [Q, 1] column and a [1, Q] row, B transposed to [N, Q] — so every
+    block's last two dims are whole array dims, the TPU tiling rule for
+    blocks that are not (8, 128)-aligned.
     """
     B, nc, Q, nh, hp = x.shape
     N = Bm.shape[-1]
-    grid = (B, nc, nh)
-    kern = functools.partial(_kernel, Q=Q)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
+    sq = pl.squeezed
+    cum_h = cum.transpose(0, 1, 3, 2)                      # [B,nc,nh,Q]
+    head = lambda b, c, h: (b, c, h, 0, 0)                 # noqa: E731
+    chunk = lambda b, c, h: (b, c, 0, 0)                   # noqa: E731
+    y, st = pl.pallas_call(
+        _kernel,
+        grid=(B, nc, nh),
         in_specs=[
-            pl.BlockSpec((1, 1, Q, 1, hp), lambda b, c, h: (b, c, 0, h, 0)),
-            pl.BlockSpec((1, 1, Q, 1), lambda b, c, h: (b, c, 0, h)),
-            pl.BlockSpec((1, 1, Q, N), lambda b, c, h: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, Q, N), lambda b, c, h: (b, c, 0, 0)),
+            pl.BlockSpec((sq, sq, sq, Q, hp), head),
+            pl.BlockSpec((sq, sq, sq, Q, 1), head),
+            pl.BlockSpec((sq, sq, sq, 1, Q), head),
+            pl.BlockSpec((sq, sq, N, Q), chunk),
+            pl.BlockSpec((sq, sq, Q, N), chunk),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, Q, 1, hp), lambda b, c, h: (b, c, 0, h, 0)),
-            pl.BlockSpec((1, 1, 1, N, hp), lambda b, c, h: (b, c, h, 0, 0)),
+            pl.BlockSpec((sq, sq, sq, Q, hp), head),
+            pl.BlockSpec((sq, sq, sq, N, hp), head),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, nc, Q, nh, hp), jnp.float32),
+            jax.ShapeDtypeStruct((B, nc, nh, Q, hp), jnp.float32),
             jax.ShapeDtypeStruct((B, nc, nh, N, hp), jnp.float32),
         ],
         interpret=interpret,
-    )(x, cum, Bm, Cm)
+    )(x.transpose(0, 1, 3, 2, 4), cum_h[..., None], cum_h[..., None, :],
+      Bm.swapaxes(-1, -2), Cm)
+    return y.transpose(0, 1, 3, 2, 4), st

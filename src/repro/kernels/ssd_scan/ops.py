@@ -11,6 +11,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .kernel import ssd_chunk_pallas
 
 
@@ -20,8 +21,7 @@ def ssd_forward(x, dt, A, Bm, Cm, chunk: int, *,
 
     x: [B,S,nh,hp]; dt: [B,S,nh] fp32; A: [nh]; Bm/Cm: [B,S,N].
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     Bsz, S, nh, hp = x.shape
     N = Bm.shape[-1]
     Q = min(chunk, S)

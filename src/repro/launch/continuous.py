@@ -44,6 +44,7 @@ from ..models import paper_models, transformer as T
 from ..models.config import ModelConfig
 from . import parambuf
 from . import steps as S
+from .compile_cache import enable_compile_cache
 
 
 class ContinuousServer:
@@ -258,6 +259,7 @@ def main():
     ap.add_argument("--K", type=int, default=6)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     from ..configs import get_config
     from ..fl.runtime import MFLExperiment

@@ -56,8 +56,7 @@ def make_population_mesh(n_scenario: int | None = None,
     leave one side None to infer it; with both None all devices go to the
     clients axis (scenario=1).  Returns ``None`` on a single device, like
     ``make_sweep_mesh`` — callers fall back to the unsharded vmap."""
-    import numpy as np
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType
 
     devs = jax.devices()
     total = len(devs)
@@ -74,8 +73,11 @@ def make_population_mesh(n_scenario: int | None = None,
         raise ValueError(
             f"mesh {n_scenario}x{n_clients} needs {n} devices, "
             f"have {total}")
-    return Mesh(np.asarray(devs[:n]).reshape(n_scenario, n_clients),
-                ("scenario", "clients"))
+    # jax.make_mesh orders the devices along the chips' physical layout
+    # (a plain reshape of jax.devices() need not); Auto axes keep the
+    # shard_map sweeps' semantics
+    return jax.make_mesh((n_scenario, n_clients), ("scenario", "clients"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devs[:n])
 
 
 def data_axes(mesh) -> tuple:

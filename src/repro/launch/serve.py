@@ -24,6 +24,7 @@ import numpy as np
 from ..configs import get_config
 from ..models import transformer as T, encdec
 from . import steps as S
+from .compile_cache import enable_compile_cache
 
 
 def teacher_forced_prefill(serve_step, params, cache, prompts):
@@ -104,7 +105,9 @@ def main():
     ap.add_argument("--teacher-forced", action="store_true",
                     help="legacy per-token prefill (A/B baseline)")
     ap.add_argument("--seed", type=int, default=0)
-    serve(ap.parse_args())
+    args = ap.parse_args()
+    enable_compile_cache()
+    serve(args)
 
 
 if __name__ == "__main__":

@@ -140,16 +140,14 @@ def scenario_shard_map(fn, mesh: Mesh, n_args: int,
     programs (no cross-scenario collectives), so this is pure SPMD fan-out —
     wall-clock divides by the device count.  Pad the grid first
     (``pad_leading_axis``) when it doesn't divide the mesh."""
-    from jax.experimental.shard_map import shard_map
-
     sharded = set(sharded_args)
     in_specs = tuple(P("scenario") if i in sharded else P()
                      for i in range(n_args))
-    # check_rep=False: the replication checker mis-types lax.scan carries
-    # that mix replicated and sharded leaves (upstream jax limitation); the
-    # sweeps are collective-free, so the check buys nothing here
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=P("scenario"), check_rep=False)
+    # check_vma=False: the varying-axes checker mis-types lax.scan carries
+    # that mix replicated and sharded leaves; the sweeps are
+    # collective-free, so the check buys nothing here
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=P("scenario"), check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -191,13 +189,11 @@ def population_shard_map(fn, mesh: Mesh, in_specs, out_specs):
     uniform leading-axis split, population sweeps shard different arguments
     along different axes: the V grid over "scenario", the client store and
     per-client randomness over "clients", the carry replicated.
-    check_rep=False for the same scan-carry reason as ``scenario_shard_map``;
+    check_vma=False for the same scan-carry reason as ``scenario_shard_map``;
     the only collectives are the cohort gather's psums / all_gathers over
     "clients", whose outputs are replicated by construction."""
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def pad_leading_axis(tree, multiple: int):

@@ -29,6 +29,7 @@ from ..configs import get_config
 from ..data.tokens import TokenStream, vlm_batch
 from ..optim import warmup_cosine, adamw
 from . import steps as S
+from .compile_cache import enable_compile_cache
 
 
 def train_standard(args):
@@ -96,6 +97,7 @@ def main():
     ap.add_argument("--n-samples", type=int, default=800)
     ap.add_argument("--V", type=float, default=1.0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "federated":
         train_federated(args)
     else:

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import fusion as core_fusion
 from repro.core.convergence import (grad_gram, tracker_update_cohort,
@@ -66,7 +65,7 @@ def test_vjp_dlogits_vs_f64_ref(M, T, V, bt, bv, kind):
         return (f * cf).sum() + (m * cm).sum()
 
     dl = jax.jit(jax.grad(scalar))(logits)
-    with enable_x64():
+    with jax.enable_x64(True):
         d_ref, _, _ = fusion_loss_ref_grads(logits, labels, avail, cf, cm)
     np.testing.assert_allclose(np.asarray(dl), np.asarray(d_ref),
                                rtol=1e-4, atol=2e-5)
@@ -104,7 +103,7 @@ def test_fused_partials_gsq_gdot(M, T, V, bt, bv, kind):
     dl, gsq, gdot = kops.fusion_loss_grads(logits, labels, avail, cf, cm,
                                            block_t=bt, block_v=bv,
                                            interpret=True)
-    with enable_x64():
+    with jax.enable_x64(True):
         d_ref, gsq_ref, gdot_ref = fusion_loss_ref_grads(
             logits, labels, avail, cf, cm)
     np.testing.assert_allclose(np.asarray(dl), np.asarray(d_ref),
