@@ -1,0 +1,13 @@
+"""The benchmark's own tests run on the CPU, at tiny sizes.
+
+    PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest -q bench/tests
+"""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
