@@ -85,7 +85,6 @@ dense masked path, and interleaved exact zeros do not move f32 sums
 from __future__ import annotations
 
 import functools
-import time
 import warnings
 from typing import Any, Dict, NamedTuple, Optional
 
@@ -425,6 +424,7 @@ class FusedRoundEngine:
     # ------------------------------------------------------------------
     # the fused program
     # ------------------------------------------------------------------
+    @jax.named_scope("round")
     def _round_step(self, carry: FusedCarry, xs: RoundXs, store, test_set,
                     overrides=None, axis_name: Optional[str] = None):
         """One round.  ``store`` is the (possibly shard-local)
@@ -437,64 +437,78 @@ class FusedRoundEngine:
         vmapped V — or, for scenario grids, any per-scenario context:
         gamma/tau_rem/tau_cmp/e_cmp/has/D/wbar...); ``test_set`` is the
         ``(features, labels)`` held-out split the round evaluates on — the
-        engine's own, or a scenario's in a scenario grid."""
+        engine's own, or a scenario's in a scenario grid.
+
+        Every op carries its stage in its name scope: ``round/policy``
+        (steps 0-2), ``round/gather`` (step 3 up to the cohort
+        ``lax.cond``), ``round/bgd`` (that ``lax.cond``),
+        ``round/aggregate`` (steps 4-6) and ``round/eval`` (step 7), so a
+        profiler trace of any entry that runs this body splits device time
+        by stage (``bench/scopes.py`` reads it)."""
         self.trace_count += 1
         tf, tl = test_set
 
-        # 0. under a client-sharded mesh the *vector* physics stays dense +
-        # replicated: reassemble the full channel draw from the shards
-        h = xs.h if axis_name is None else \
-            lax.all_gather(xs.h, axis_name, tiled=True)
+        with jax.named_scope("policy"):
+            # 0. under a client-sharded mesh the *vector* physics stays
+            # dense + replicated: reassemble the full channel draw from the
+            # shards
+            h = xs.h if axis_name is None else \
+                lax.all_gather(xs.h, axis_name, tiled=True)
 
-        # 1. server decision: the scheduler's traced policy core (JCSBA's
-        # population-batched solve, or a baseline's traced schedule) — the
-        # policy state (warm start / cursor / ...) threads through the carry
-        data = dict(self._solver_tmpl)
-        if overrides:
-            data.update(overrides)      # e.g. a vmapped V for scenario sweeps
-        data["Q"], data["h"] = carry.Q, h
-        data["zeta2"] = jnp.square(carry.zeta)
-        data["delta2"] = jnp.square(carry.delta)
-        if axis_name is not None and hasattr(self.policy, "hp"):
-            # the KKT B_min bisection is the solver's only per-client
-            # *compute* (30 fixed iterations × K): run it shard-locally on
-            # this shard's slice and all_gather — elementwise, so bit-exact
-            K_loc = xs.h.shape[0]
-            off = lax.axis_index(axis_name) * K_loc
-            sl = lambda x: lax.dynamic_slice_in_dim(x, off, K_loc)  # noqa: E731
-            bl, okl = _bmin(sl(data["gamma"]), xs.h, sl(data["tau_rem"]),
-                            data["B_max"], data["p_tx"], data["N0"],
-                            self.policy.hp)
-            data["bmin"] = lax.all_gather(bl, axis_name, tiled=True)
-            data["bmin_ok"] = lax.all_gather(okl, axis_name, tiled=True)
-        pstate, a, B, J, drop_rows, idx = self.policy.step_full(
-            carry.policy, data, carry.model_dist,
-            jax.random.PRNGKey(xs.draw_seed))
+            # 1. server decision: the scheduler's traced policy core
+            # (JCSBA's population-batched solve, or a baseline's traced
+            # schedule) — the policy state (warm start / cursor / ...)
+            # threads through the carry
+            data = dict(self._solver_tmpl)
+            if overrides:
+                data.update(overrides)  # e.g. a vmapped V for scenario sweeps
+            data["Q"], data["h"] = carry.Q, h
+            data["zeta2"] = jnp.square(carry.zeta)
+            data["delta2"] = jnp.square(carry.delta)
+            if axis_name is not None and hasattr(self.policy, "hp"):
+                # the KKT B_min bisection is the solver's only per-client
+                # *compute* (30 fixed iterations × K): run it shard-locally
+                # on this shard's slice and all_gather — elementwise, so
+                # bit-exact
+                K_loc = xs.h.shape[0]
+                off = lax.axis_index(axis_name) * K_loc
+                sl = lambda x: lax.dynamic_slice_in_dim(x, off, K_loc)  # noqa: E731
+                bl, okl = _bmin(sl(data["gamma"]), xs.h, sl(data["tau_rem"]),
+                                data["B_max"], data["p_tx"], data["N0"],
+                                self.policy.hp)
+                data["bmin"] = lax.all_gather(bl, axis_name, tiled=True)
+                data["bmin_ok"] = lax.all_gather(okl, axis_name, tiled=True)
+            pstate, a, B, J, drop_rows, idx = self.policy.step_full(
+                carry.policy, data, carry.model_dist,
+                jax.random.PRNGKey(xs.draw_seed))
 
-        # 2. latency feasibility (C4): scheduled-but-late ⇒ failure — energy
-        # is spent, nothing is uploaded
-        r = rate(jnp.maximum(B, B_LO), h, self._p_tx, self._N0)
-        tcom = jnp.where(a, data["gamma"] / jnp.maximum(r, 1e-30), 0.0)
-        ok = a & (tcom + data["tau_cmp"] <= self._tau_max + 1e-12)
+            # 2. latency feasibility (C4): scheduled-but-late ⇒ failure —
+            # energy is spent, nothing is uploaded
+            r = rate(jnp.maximum(B, B_LO), h, self._p_tx, self._N0)
+            tcom = jnp.where(a, data["gamma"] / jnp.maximum(r, 1e-30), 0.0)
+            ok = a & (tcom + data["tau_cmp"] <= self._tau_max + 1e-12)
 
-        # 3. cohort gather + masked BGD updates (Eq. 7) on the [J] stack.
-        # The policy's index vector lists scheduled clients first (ascending)
-        # with unscheduled padding; ``ok_c`` masks failures and padding alike,
-        # so a padding slot contributes exact zeros everywhere downstream.
-        if axis_name is None:
-            cohort = store.take(idx)
-            seeds_c = jnp.take(xs.client_seeds, idx)
-        else:
-            cohort = jax.tree.map(
-                lambda x: _gather_rows(x, idx, axis_name), store)
-            seeds_c = _gather_rows(xs.client_seeds, idx, axis_name)
-        Jc = idx.shape[0]
-        ok_c = jnp.take(ok, idx)
-        drop = {m: drop_rows[i] for m, i in self._drop_rows.items()
-                if m in self.mods}       # empty for policies without dropout
-        drop_c = {m: jnp.take(d, idx) for m, d in drop.items()}
-        upload_c = agg.upload_masks_traced(ok_c, cohort.has_modality, drop_c)
-        avail_c = {m: upload_c[m].astype(jnp.float32) for m in self.mods}
+        with jax.named_scope("gather"):
+            # 3. cohort gather + masked BGD updates (Eq. 7) on the [J]
+            # stack.  The policy's index vector lists scheduled clients
+            # first (ascending) with unscheduled padding; ``ok_c`` masks
+            # failures and padding alike, so a padding slot contributes
+            # exact zeros everywhere downstream.
+            if axis_name is None:
+                cohort = store.take(idx)
+                seeds_c = jnp.take(xs.client_seeds, idx)
+            else:
+                cohort = jax.tree.map(
+                    lambda x: _gather_rows(x, idx, axis_name), store)
+                seeds_c = _gather_rows(xs.client_seeds, idx, axis_name)
+            Jc = idx.shape[0]
+            ok_c = jnp.take(ok, idx)
+            drop = {m: drop_rows[i] for m, i in self._drop_rows.items()
+                    if m in self.mods}   # empty for policies without dropout
+            drop_c = {m: jnp.take(d, idx) for m, d in drop.items()}
+            upload_c = agg.upload_masks_traced(ok_c, cohort.has_modality,
+                                               drop_c)
+            avail_c = {m: upload_c[m].astype(jnp.float32) for m in self.mods}
 
         def run_cohort(args):
             params, avail, seeds = args
@@ -510,53 +524,64 @@ class FusedRoundEngine:
             return (newp, jax.tree.map(jnp.zeros_like, newp),
                     {m: jnp.zeros(Jc, jnp.float32) for m in self.mods})
 
-        newp_c, grads_c, dist_sq_c = lax.cond(
-            ok.any(), run_cohort, skip_cohort,
-            (carry.params, avail_c, seeds_c))
+        with jax.named_scope("bgd"):
+            newp_c, grads_c, dist_sq_c = lax.cond(
+                ok.any(), run_cohort, skip_cohort,
+                (carry.params, avail_c, seeds_c))
 
-        # 4. Eq. 12 aggregation on the cohort stack + ζ/δ tracker refresh.
-        # Every contributor is in the cohort by construction, so the weight
-        # renormalisation over J equals the dense one over K; the dense [K]
-        # weight rows the aux records keep are the segment-sum scatter.
-        # The trackers consume the per-modality gradient Gram matrix
-        # G = Σ_leaves X Xᵀ [J, J]: ζ² = wᵀGw and δ_j² = G_jj − 2(Gw)_j +
-        # wᵀGw, so the refresh needs no aggregated-gradient pytree and no
-        # second O(J·|θ|) reduction pass over the gradient stack.
-        w_c = agg.stacked_weights_traced(cohort.sizes, upload_c)
-        new_params = agg.aggregate_stacked_traced(carry.params, newp_c, w_c)
-        w = agg.cohort_weights_dense(w_c, idx, self.K)
-        zs, ds = [], []
-        for i, m in enumerate(self.mods):
-            z_m, d_m = tracker_update_gram(
-                carry.zeta[i], carry.delta[i], grad_gram(grads_c[m]),
-                w_c[m], upload_c[m], idx, data["has"][i], self.staleness)
-            zs.append(z_m)
-            ds.append(d_m)
+        with jax.named_scope("aggregate"):
+            # 4. Eq. 12 aggregation on the cohort stack + ζ/δ tracker
+            # refresh.  Every contributor is in the cohort by construction,
+            # so the weight renormalisation over J equals the dense one
+            # over K; the dense [K] weight rows the aux records keep are the
+            # segment-sum scatter.  The trackers consume the per-modality
+            # gradient Gram matrix G = Σ_leaves X Xᵀ [J, J]: ζ² = wᵀGw and
+            # δ_j² = G_jj − 2(Gw)_j + wᵀGw, so the refresh needs no
+            # aggregated-gradient pytree and no second O(J·|θ|) reduction
+            # pass over the gradient stack.
+            w_c = agg.stacked_weights_traced(cohort.sizes, upload_c)
+            new_params = agg.aggregate_stacked_traced(carry.params, newp_c,
+                                                      w_c)
+            w = agg.cohort_weights_dense(w_c, idx, self.K)
+            zs, ds = [], []
+            for i, m in enumerate(self.mods):
+                z_m, d_m = tracker_update_gram(
+                    carry.zeta[i], carry.delta[i], grad_gram(grads_c[m]),
+                    w_c[m], upload_c[m], idx, data["has"][i],
+                    self.staleness)
+                zs.append(z_m)
+                ds.append(d_m)
 
-        # 5. Lyapunov queue recursion (§V-A) + energy accounting
-        used = a.astype(jnp.float32) * (self._p_tx * tcom + data["e_cmp"])
-        Qn = queue_update(carry.Q, used, self._E_add)
-        spent = carry.spent + used
+            # 5. Lyapunov queue recursion (§V-A) + energy accounting
+            used = a.astype(jnp.float32) * (self._p_tx * tcom
+                                            + data["e_cmp"])
+            Qn = queue_update(carry.Q, used, self._E_add)
+            spent = carry.spent + used
 
-        # 6. ‖θ_k − θ⁰‖ for participants (Selection-scheduler bookkeeping):
-        # cohort-local distances scattered back to the dense row
-        d_sq_c = sum(dist_sq_c[m] * avail_c[m] for m in self.mods)
-        dist_k = agg.scatter_cohort_rows(
-            jnp.where(ok_c, jnp.sqrt(d_sq_c), 0.0), idx, self.K)
-        model_dist = jnp.where(ok, dist_k, carry.model_dist)
+            # 6. ‖θ_k − θ⁰‖ for participants (Selection-scheduler
+            # bookkeeping): cohort-local distances scattered back to the
+            # dense row
+            d_sq_c = sum(dist_sq_c[m] * avail_c[m] for m in self.mods)
+            dist_k = agg.scatter_cohort_rows(
+                jnp.where(ok_c, jnp.sqrt(d_sq_c), 0.0), idx, self.K)
+            model_dist = jnp.where(ok, dist_k, carry.model_dist)
+            new_carry = FusedCarry(new_params, pstate, Qn, spent,
+                                   jnp.stack(zs), jnp.stack(ds), model_dist)
+            energy_total = spent.sum()
 
-        # 7. device-resident eval of the fresh globals on the held-out split
-        # (the host loop's adapter.evaluate, fused behind the cadence flag —
-        # only the branch that actually runs costs anything at runtime)
-        metrics = lax.cond(
-            xs.eval_flag,
-            lambda p: eval_metrics(p, tf, tl, logits_fn=self._eval_logits),
-            lambda p: nan_metrics(tf),
-            new_params)
+        with jax.named_scope("eval"):
+            # 7. device-resident eval of the fresh globals on the held-out
+            # split (the host loop's adapter.evaluate, fused behind the
+            # cadence flag — only the branch that actually runs costs
+            # anything at runtime)
+            metrics = lax.cond(
+                xs.eval_flag,
+                lambda p: eval_metrics(p, tf, tl, logits_fn=self._eval_logits),
+                lambda p: nan_metrics(tf),
+                new_params)
 
-        new_carry = FusedCarry(new_params, pstate, Qn, spent,
-                               jnp.stack(zs), jnp.stack(ds), model_dist)
-        aux = RoundAux(a, ok, J, w, spent.sum(), drop, metrics, xs.eval_flag)
+        aux = RoundAux(a, ok, J, w, energy_total, drop, metrics,
+                       xs.eval_flag)
         return new_carry, aux
 
     def _scan_steps(self, carry: FusedCarry, xs: RoundXs, store, test_set):
@@ -747,11 +772,15 @@ class FusedRoundEngine:
 
     # ------------------------------------------------------------------
     def run(self, carry: FusedCarry, xs: RoundXs, scanned: bool):
-        """Execute and time; returns (carry, aux-on-host, wall seconds)."""
-        t0 = time.perf_counter()
-        if scanned:
-            carry, aux = self.scan(carry, xs)
-        else:
-            carry, aux = self.step(carry, xs)
-        aux = jax.tree.map(np.asarray, jax.block_until_ready(aux))
-        return carry, aux, time.perf_counter() - t0
+        """Execute and wait; returns (carry, aux on the host, round0), where
+        ``round0`` is the experiment's first round of the call (0 for an
+        engine built ``from_store``).  No wall time: the host phases are
+        profiler spans tagged with ``round0``, ``mfl.dispatch`` (argument
+        transfer and launch) and ``mfl.fetch`` (the wait for the program and
+        the copy of ``aux``)."""
+        round0 = 0 if self.exp is None else self.exp._round
+        with jax.profiler.TraceAnnotation("mfl.dispatch", round0=round0):
+            carry, aux = (self.scan if scanned else self.step)(carry, xs)
+        with jax.profiler.TraceAnnotation("mfl.fetch", round0=round0):
+            aux = jax.tree.map(np.asarray, jax.block_until_ready(aux))
+        return carry, aux, round0

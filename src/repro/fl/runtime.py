@@ -86,6 +86,12 @@ from ..wireless.schedulers import (ScheduleContext, Scheduler, make_scheduler)
 from .client import make_adapter
 
 
+def _span(name: str, round0: int):
+    """A host phase of a fused call as a profiler span (free unless a
+    ``jax.profiler.trace`` is active), tagged with the call's first round."""
+    return jax.profiler.TraceAnnotation(name, round0=round0)
+
+
 def jnp_or_np(x):
     """Record/JSON-boundary normalizer: accepts jnp OR np values (e.g. fields
     produced under jit) and returns plain Python objects — 0-d arrays become
@@ -110,6 +116,9 @@ class RoundRecord:
     failures: List[int]
     energy_total: float
     metrics: Dict[str, float]
+    #: host scheduler wall time; NaN on the fused path, which has no
+    #: scheduler time of its own (the ``mfl.*`` profiler spans of
+    #: ``run_scanned`` / ``run_round`` time its host phases instead)
     sched_time_s: float
     #: modality -> sorted clients that dropped it this round ([28]'s
     #: modality-dropout baseline; empty for every other policy)
@@ -301,12 +310,12 @@ class MFLExperiment:
             self._carry = self._fused_engine.init_carry()
         return self._fused_engine
 
-    def _decode_fused_round(self, t: int, aux, sched_time: float
-                            ) -> RoundRecord:
+    def _decode_fused_round(self, t: int, aux) -> RoundRecord:
         """Host-side decoder: traced schedule/energy/eval arrays →
         RoundRecord.  Metrics come from the device-resident eval — real only
         on rounds the cadence flagged (``aux.eval_mask``); the NaN fillers of
-        skipped rounds never reach a record."""
+        skipped rounds never reach a record.  ``sched_time_s`` is NaN: no
+        scheduler time exists on the fused path."""
         a = np.asarray(aux.a, bool)
         ok = np.asarray(aux.ok, bool)
         self.last_weights = {m: np.asarray(aux.weights[m])
@@ -318,26 +327,27 @@ class MFLExperiment:
                    for m, d in aux.drop.items()}
         return RoundRecord.make(t, sorted(np.flatnonzero(ok)),
                                 sorted(np.flatnonzero(a & ~ok)),
-                                aux.energy_total, metrics, sched_time,
+                                aux.energy_total, metrics, float("nan"),
                                 {m: ks for m, ks in dropped.items()
                                  if len(ks)})
 
     def _run_round_fused(self) -> RoundRecord:
-        # note: the record's sched_time_s holds the WHOLE fused-step wall
-        # time (the stages are inseparable inside one program; round 0
-        # includes jit compilation) — the host path times only the scheduler
         from .fused_round import draw_round_xs
         eng = self._get_fused_engine()
-        xs = draw_round_xs(self, 1)
-        xs = jax.tree.map(lambda x: x[0], xs)
-        self._carry, aux, wall = eng.run(self._carry, xs, scanned=False)
-        rec = self._decode_fused_round(self._round, aux, wall)
-        self.history.append(rec)
-        self._round += 1
-        # keep the public host-side mirrors (global_params, queues, bound,
-        # model_dist) live — a device->host copy, not a round-trip: the
-        # carry stays the compute chain's source of truth
-        eng.export_carry(self._carry)
+        t = self._round
+        with _span("mfl.call", t):
+            with _span("mfl.draw_xs", t):
+                xs = jax.tree.map(lambda x: x[0], draw_round_xs(self, 1))
+            self._carry, aux, _ = eng.run(self._carry, xs, scanned=False)
+            with _span("mfl.decode", t):
+                rec = self._decode_fused_round(t, aux)
+                self.history.append(rec)
+                self._round += 1
+            # keep the public host-side mirrors (global_params, queues,
+            # bound, model_dist) live — a device->host copy, not a
+            # round-trip: the carry stays the compute chain's source of truth
+            with _span("mfl.export", t):
+                eng.export_carry(self._carry)
         return rec
 
     def run_scanned(self, rounds: int) -> List[RoundRecord]:
@@ -348,23 +358,30 @@ class MFLExperiment:
         evaluated *inside* the scan on every round of the ``eval_every`` grid
         (the device-resident ``fl.eval`` pass — intermediate global params
         still never materialise on host), so one scan yields the full
-        accuracy curve; ``sched_time_s`` records the mean per-round wall time
-        of the whole fused scan (compile included on the first call), not the
-        host path's scheduler-only time."""
+        accuracy curve.
+
+        Under ``jax.profiler.trace`` each call leaves an ``mfl.call`` span
+        holding, in order, ``mfl.draw_xs`` (the rounds' randomness),
+        ``mfl.dispatch`` and ``mfl.fetch`` (``FusedRoundEngine.run``),
+        ``mfl.decode`` (the records) and ``mfl.export`` (the host mirrors),
+        all tagged ``round0`` = the call's first round."""
         if not self.fused:
             raise RuntimeError("run_scanned requires engine='fused'")
         from .fused_round import draw_round_xs
         eng = self._get_fused_engine()
-        xs = draw_round_xs(self, rounds)
-        self._carry, auxs, wall = eng.run(self._carry, xs, scanned=True)
-        start, per = self._round, wall / max(rounds, 1)
-        recs = []
-        for i in range(rounds):
-            aux = jax.tree.map(lambda x: x[i], auxs)
-            recs.append(self._decode_fused_round(start + i, aux, per))
-        self.history.extend(recs)
-        self._round += rounds
-        eng.export_carry(self._carry)     # host mirrors stay live (see above)
+        start = self._round
+        with _span("mfl.call", start):
+            with _span("mfl.draw_xs", start):
+                xs = draw_round_xs(self, rounds)
+            self._carry, auxs, _ = eng.run(self._carry, xs, scanned=True)
+            with _span("mfl.decode", start):
+                recs = [self._decode_fused_round(
+                            start + i, jax.tree.map(lambda x: x[i], auxs))
+                        for i in range(rounds)]
+                self.history.extend(recs)
+                self._round += rounds
+            with _span("mfl.export", start):
+                eng.export_carry(self._carry)   # host mirrors stay live
         return recs
 
     # ------------------------------------------------------------------
@@ -482,10 +499,11 @@ class MFLExperiment:
             rec = self.run_round()
             if verbose and rec.metrics:
                 acc = rec.metrics.get("multimodal", float("nan"))
+                sched = ("" if np.isnan(rec.sched_time_s) else
+                         f"sched={rec.sched_time_s * 1e3:.1f}ms ")
                 print(f"[{self.scheduler.name}] round {rec.round:4d} "
                       f"acc={acc:.4f} E={rec.energy_total:.3f}J "
-                      f"sched={rec.sched_time_s * 1e3:.1f}ms "
-                      f"part={rec.participants}")
+                      f"{sched}part={rec.participants}")
         return self.history
 
     # ------------------------------------------------------------------
@@ -554,7 +572,9 @@ class MFLExperiment:
             if rec.metrics:
                 out = dict(rec.metrics)
                 out["energy_total"] = self.history[-1].energy_total
-                out["mean_sched_time_s"] = float(np.mean(
-                    [r.sched_time_s for r in self.history]))
+                sched = [r.sched_time_s for r in self.history
+                         if np.isfinite(r.sched_time_s)]
+                if sched:       # host engines only: fused records hold NaN
+                    out["mean_sched_time_s"] = float(np.mean(sched))
                 return out
         return {}
