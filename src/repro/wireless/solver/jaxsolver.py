@@ -2,13 +2,16 @@
 search over antibodies × KKT bandwidth bisection × Theorem-1 bound) as one
 JAX program per round.
 
-The program evaluates the full antibody population per generation: J₂(a) for
-every candidate is computed by a candidate-vmapped, participant-masked
+Each generation evaluates its new rows — the clones/mutants and elites plus
+the fresh random rows, which are drawn up front — as one population: J₂(a)
+and the KKT bandwidths B come from one candidate-vmapped, participant-masked
 fixed-iteration bisection stack (see ``common`` for the numerical
-conventions), the bound term comes from ``core.convergence.objective_batched``
-and everything runs under a single ``jax.jit`` with ``lax.fori_loop`` over
-generations.  Random draws come from ``make_draws`` (``jax.random``) so the
-float64 numpy mirror in ``ref.py`` can consume the identical bits.
+conventions), and the bound term from ``core.convergence.objective_batched``.
+Every row's B is carried beside its J, so the winner's allocation is never
+solved again: a solve runs G + 1 sequential κ-bisection chains.  Everything
+runs under a single ``jax.jit`` with ``lax.fori_loop`` over generations.
+Random draws come from ``make_draws`` (``jax.random``) so the float64 numpy
+mirror in ``ref.py`` can consume the identical bits.
 
 ``solve_core`` is the pure jnp entry point — ``policies.JCSBAPolicy`` builds
 its traced step on it and benchmark sweep drivers wrap it in their own
@@ -106,7 +109,9 @@ def _phi_inv(kappa, bmin, phi_b, Q, gamma, h, B_max, p_tx, N0,
         under = _phi(mid, Q, gamma, h, p_tx, N0) < kappa
         return jnp.where(under, mid, lo), jnp.where(under, hi, mid)
 
-    lo, hi = lax.fori_loop(0, hp.n_bisect_b, body, (lo, hi))
+    # unrolled: the loop runs inside every κ step, and on a TPU a trip of
+    # these few-vreg arrays costs its loop overhead, not its arithmetic
+    lo, hi = lax.fori_loop(0, hp.n_bisect_b, body, (lo, hi), unroll=True)
     return jnp.where(pinned, bmin, 0.5 * (lo + hi))
 
 
@@ -231,24 +236,28 @@ def solve_core(data: dict, seeds, key, hp: SolverHyper):
         B, feas = allocate_batch(A, bmin, ok, data["Q"], data["gamma"],
                                  data["h"], data["B_max"], data["p_tx"],
                                  data["N0"], hp)
-        return objective_batch(A, B, feas, data)
+        return objective_batch(A, B, feas, data), B
 
-    def fold_best(pop, vals, best_a, best_J):
+    def fold_best(pop, vals, Bs, best):
+        best_a, best_J, best_B = best
         i = jnp.argmin(vals)
         better = vals[i] < best_J
         return (jnp.where(better, pop[i], best_a),
-                jnp.where(better, vals[i], best_J))
+                jnp.where(better, vals[i], best_J),
+                jnp.where(better, Bs[i], best_B))
 
     init, mut, fresh = make_draws(key, K, hp)
     seeds = jnp.asarray(seeds, bool)
     pop0 = init.at[0].set(seeds[0]).at[1].set(seeds[1])
 
-    # J is purely row-wise, so the population's values are carried across
-    # generations and only *new* genotypes (clones/mutants + fresh rows) are
-    # evaluated — the batched analogue of the sequential path's memoisation.
+    # J and B are purely row-wise, so the population's values and bandwidths
+    # are carried across generations and only *new* genotypes are evaluated
+    # — the batched analogue of the sequential path's memoisation.  The fresh
+    # rows are drawn up front, so they share the candidates' bisection chain:
+    # one chain a generation, not two.
     def gen(g, carry):
-        pop, vals, best_a, best_J = carry
-        best_a, best_J = fold_best(pop, vals, best_a, best_J)
+        pop, vals, Bs, best = carry
+        best = fold_best(pop, vals, Bs, best)
         aff = _affinity(vals, hp)
         ham = (pop[:, None, :] ^ pop[None, :, :]).sum(-1)
         con = (ham <= hp.dis).astype(aff.dtype).mean(-1)      # Eq. 51-52
@@ -256,22 +265,19 @@ def solve_core(data: dict, seeds, key, hp: SolverHyper):
         elites = pop[jnp.argsort(-inc)[:hp.n_elite]]
         clones = jnp.repeat(elites, hp.mu, axis=0)            # μ-fold cloning
         mutants = clones ^ mut[g]
-        cand = jnp.concatenate([mutants, elites], axis=0)
-        cand_vals = J_batch(cand)
-        cand_aff = _affinity(cand_vals, hp)
+        rows = jnp.concatenate([mutants, elites, fresh[g]], axis=0)
+        new_vals, new_B = J_batch(rows)
+        cand_aff = _affinity(new_vals[:hp.n_cand], hp)
         order = jnp.argsort(-cand_aff)[:hp.n_keep]
-        pop = jnp.concatenate([cand[order], fresh[g]], axis=0)
-        vals = jnp.concatenate([cand_vals[order], J_batch(fresh[g])])
-        return pop, vals, best_a, best_J
+        keep = jnp.concatenate([order, hp.n_cand + jnp.arange(hp.n_fresh)])
+        return rows[keep], new_vals[keep], new_B[keep], best
 
-    carry = (pop0, J_batch(pop0), jnp.zeros(K, bool),
-             jnp.asarray(jnp.inf, jnp.float32))
-    pop, vals, best_a, best_J = lax.fori_loop(0, hp.G, gen, carry)
-    best_a, best_J = fold_best(pop, vals, best_a, best_J)     # final gen check
-    B, _ = allocate_batch(best_a[None], bmin, ok, data["Q"], data["gamma"],
-                          data["h"], data["B_max"], data["p_tx"],
-                          data["N0"], hp)
-    return best_a, best_J, B[0]
+    # no row beats +inf → the all-zeros antibody, whose allocation is zeros
+    best0 = (jnp.zeros(K, bool), jnp.asarray(jnp.inf, jnp.float32),
+             jnp.zeros(K, bmin.dtype))
+    pop, vals, Bs, best = lax.fori_loop(0, hp.G, gen,
+                                        (pop0, *J_batch(pop0), best0))
+    return fold_best(pop, vals, Bs, best)                     # final gen check
 
 
 @partial(jax.jit, static_argnames="hp")

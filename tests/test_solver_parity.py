@@ -148,6 +148,76 @@ def test_immune_solve_parity(seed):
     assert np.allclose(Bj, Bn, rtol=1e-3, atol=2.0)
 
 
+def _solve_and_resolve(data, seeds, seed_int, hp=HP):
+    """solve_core's (a*, J*, B*) and, in the same jitted program, B re-solved
+    for a* alone by allocate_batch."""
+    import jax
+
+    from repro.wireless.solver import jaxsolver as sjax
+
+    def both(d, seeds, key):
+        a, J, B = sjax.solve_core(d, seeds, key, hp)
+        bmin, ok = sjax._bmin(d["gamma"], d["h"], d["tau_rem"], d["B_max"],
+                              d["p_tx"], d["N0"], hp)
+        B_re, _ = sjax.allocate_batch(a[None], bmin, ok, d["Q"], d["gamma"],
+                                      d["h"], d["B_max"], d["p_tx"], d["N0"],
+                                      hp)
+        return a, J, B, B_re[0]
+
+    out = jax.jit(both)(sjax.to_device(data), np.asarray(seeds, bool),
+                        jax.random.PRNGKey(seed_int))
+    return tuple(np.asarray(x) for x in out)
+
+
+@pytest.mark.parametrize("seed,tau_max", [(0, None), (1, None), (2, None),
+                                          (3, None), (5, 1e-6)],
+                         ids=["seed0", "seed1", "seed2", "seed3",
+                              "only-empty-feasible"])
+def test_solve_carried_bandwidth_is_the_resolve(seed, tau_max):
+    """The bandwidth carried beside the winning row is, bit for bit, the one
+    a fresh allocate_batch of the winner alone gives."""
+    data, *_ = _data(K=10, seed=seed, tau_max=tau_max)
+    seeds = np.zeros((2, 10), bool)
+    seeds[0] = np.random.default_rng(seed).integers(0, 2, 10).astype(bool)
+    a, J, B, B_re = _solve_and_resolve(data, seeds, 500 + seed)
+    assert np.isfinite(J)
+    assert np.array_equal(B, B_re)
+    if tau_max is not None:
+        assert not a.any() and (B == 0).all()
+    else:
+        assert a.any() and (B[a] > 0).all()
+
+
+def _kappa_chains(jaxpr, n):
+    """Carry lengths of every scan of length ``n`` in ``jaxpr`` and its
+    sub-jaxprs (a static-trip ``fori_loop`` traces to such a scan)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and eqn.params["length"] == n:
+            found.append(eqn.outvars[-1].aval.shape[0])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _kappa_chains(sub, n)
+    return found
+
+
+def test_solve_runs_one_kappa_chain_per_generation():
+    """Structural guard on the solve's sequential depth: one κ-bisection
+    chain on the initial population, one per generation on the candidates
+    and fresh rows together, and no re-solve of the winner — G + 1 chains."""
+    import jax
+
+    from repro.wireless.solver import jaxsolver as sjax
+    data, *_ = _data(K=10, seed=0)
+    jaxpr = jax.make_jaxpr(lambda d, s, k: sjax.solve_core(d, s, k, HP))(
+        sjax.to_device(data), np.zeros((2, 10), bool),
+        jax.random.PRNGKey(0)).jaxpr
+    chains = _kappa_chains(jaxpr, HP.n_bisect_k)
+    assert sorted(chains) == sorted([HP.S, HP.n_cand + HP.n_fresh])
+
+
 def test_scheduler_decision_parity_across_rounds():
     """Per-round ScheduleDecision parity: solver='jax' and solver='np' track
     the same schedule/allocation over multiple rounds (warm starts, rng
