@@ -1,24 +1,36 @@
 """Driver ``scanned``: whole rounds under one ``lax.scan`` per call.
 
 The entry the window drives is ``MFLExperiment(engine="fused").run_scanned(R)``
-with the mix's fixed R, called again and again until ``seconds`` have passed.
-Every call pregenerates its rounds' randomness on the host, runs the one
-round program, waits for it (``block_until_ready`` inside the program's own
-``FusedRoundEngine.run``), decodes the records and exports the carry.
+with the mix's fixed R.  Every call pregenerates its rounds' randomness on
+the host, runs the one round program, waits for it (``block_until_ready``
+inside the program's own ``FusedRoundEngine.run``), decodes the records and
+exports the carry.
 
 Set-up is everything up to the first timed call: imports, the corpus, the
 partition and the weights from the seed, and one warm-up call with the same
 R, which compiles the cell's program.  That warm-up call is the run's first
 R rounds; its per-round outputs and the state after it are what the
-correctness check compares with the reference.  The engine's
-``trace_count`` and JAX's compile events must not move inside the window.
+correctness check compares with the reference.
 
-With ``trace`` the window is ``trace_calls`` calls under the profiler, each
-inside a ``run_scanned`` span and the bookkeeping between them inside a
-``harness`` span.
+The window replays the warm-up call's R rounds from the seed's start state,
+call after call, until ``seconds`` have passed: before each call the carry
+on the device, the round index, the host generator's state and the history
+are put back as they were before the warm-up (``replay``).  So every call
+times the rounds the check compared, with the same work on every seed and
+on both sides of a comparison, and no call reaches a later stretch of the
+seed's trajectory, where the schedule may run empty.  A replayed round whose
+schedule or participants differ from the warm-up call's, or whose metrics
+are not finite, counts as failed.  The round program donates no buffer, so
+the kept carry stays valid.  The engine's ``trace_count`` and JAX's compile
+events must not move inside the window.
+
+With ``trace`` the window is ``trace_calls`` replayed calls under the
+profiler, each inside a ``run_scanned`` span, the restore before it and the
+bookkeeping after it inside ``harness`` spans.
 """
 from __future__ import annotations
 
+import copy
 import gc
 import math
 import shutil
@@ -100,9 +112,31 @@ def first_call(exp, rounds: int) -> Record:
                         for m in mods]))
 
 
-def _bad_rounds(recs) -> int:
-    return sum(not all(math.isfinite(v) for v in r.metrics.values())
-               for r in recs)
+def keep(exp):
+    """What ``replay`` puts back: the carry (device arrays, which no call
+    donates), the round index, the host generator's state, the history's
+    length."""
+    return (exp._carry, exp._round,
+            copy.deepcopy(exp.rng.bit_generator.state), len(exp.history))
+
+
+def replay(exp, kept) -> None:
+    """Put the kept state back, so that the next ``run_scanned(R)`` runs
+    the same rounds from the same carry and the same host draws."""
+    exp._carry, exp._round, state, n0 = kept
+    exp.rng.bit_generator.state = state
+    del exp.history[n0:]
+
+
+def _failed_rounds(recs, record: Record) -> int:
+    """Rounds whose metrics are not finite, or whose participants or failed
+    uploads differ from the checked warm-up call's."""
+    a, ok = record.a, record.ok
+    return sum(
+        not all(math.isfinite(v) for v in r.metrics.values())
+        or r.participants != np.flatnonzero(ok[i]).tolist()
+        or r.failures != np.flatnonzero(a[i] & ~ok[i]).tolist()
+        for i, r in enumerate(recs))
 
 
 def _finite_params(exp) -> bool:
@@ -120,10 +154,11 @@ def _run(cfg, traffic, seed, seconds, trace, out_dir, t_start) -> dict:
     R = traffic["rounds_per_call"]
     counter = CompileCounter()
     exp = build(cfg, traffic, seed)
+    eng = exp._get_fused_engine()
+    kept = keep(exp)
     t0 = time.perf_counter()
     record = first_call(exp, R)
     warmup_s = time.perf_counter() - t0
-    eng = exp._get_fused_engine()
     traces = eng.trace_count
     compiles = counter.n
     setup_s = time.time() - t_start
@@ -133,10 +168,11 @@ def _run(cfg, traffic, seed, seconds, trace, out_dir, t_start) -> dict:
     if not trace:
         t0 = time.perf_counter()
         while True:
+            replay(exp, kept)
             recs = exp.run_scanned(R)
             rounds += R
             calls += 1
-            failed += _bad_rounds(recs)
+            failed += _failed_rounds(recs, record)
             busy_rounds += sum(bool(r.participants) for r in recs)
             if time.perf_counter() - t0 >= seconds:
                 break
@@ -149,12 +185,14 @@ def _run(cfg, traffic, seed, seconds, trace, out_dir, t_start) -> dict:
         with jax.profiler.trace(str(out_dir),
                                 profiler_options=T.profile_options()):
             for _ in range(traffic["trace_calls"]):
+                with jax.profiler.TraceAnnotation("harness"):
+                    replay(exp, kept)
                 with jax.profiler.TraceAnnotation(T.CALL_SPAN):
                     recs = exp.run_scanned(R)
                 with jax.profiler.TraceAnnotation("harness"):
                     rounds += R
                     calls += 1
-                    failed += _bad_rounds(recs)
+                    failed += _failed_rounds(recs, record)
                     participants += [(r.participants, bool(r.metrics))
                                      for r in recs]
                     busy_rounds += sum(bool(r.participants) for r in recs)
@@ -174,7 +212,9 @@ def _run(cfg, traffic, seed, seconds, trace, out_dir, t_start) -> dict:
         failed = rounds
     print(f"scanned: warm-up call {warmup_s:.3f} s (compiles the program), "
           f"set-up {setup_s:.3f} s, window {window_s:.3f} s, {calls} calls, "
-          f"{rounds} rounds, {busy_rounds} with participants", flush=True)
+          f"{rounds} rounds, {busy_rounds} with participants "
+          f"({busy_rounds / calls:g} of {R} a call), {failed} failed",
+          flush=True)
 
     holder = [exp]
 
